@@ -21,6 +21,8 @@ import subprocess
 import sys
 import traceback
 
+from repro.compile_cache import enable_compilation_cache
+
 
 def snapshot_meta() -> dict:
     """Provenance header for a ``--json`` snapshot.  Every field degrades
@@ -69,6 +71,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="OUT",
                     help="also write rows as JSON to this path")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     print("name,us_per_call,derived")
     failed = []

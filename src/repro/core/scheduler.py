@@ -92,6 +92,16 @@ class Container:
     def size(self) -> int:
         return len(self.device_ids)
 
+    def jax_devices(self) -> list:
+        """The JAX devices this container runs on: pool device id ``d`` is
+        ``jax.devices()[d % jax.device_count()]`` — the identity on a pool
+        sized to the host, and the one device when the pool is larger (a
+        logical pool over a single CPU device)."""
+        import jax
+
+        devs = jax.devices()
+        return [devs[d % len(devs)] for d in self.device_ids]
+
 
 class ResourceManager:
     def __init__(self, total_devices: int):

@@ -30,9 +30,17 @@ def make_mesh(cfg: MeshConfig) -> Mesh:
             "(dry-run scripts must set XLA_FLAGS=--xla_force_host_platform_device_count "
             "before importing jax)"
         )
-    from repro.distributed.compat import make_mesh as compat_make_mesh
+    return auto_mesh(cfg.shape, cfg.axis_names, devices=devices[:n])
 
-    return compat_make_mesh(cfg.shape, cfg.axis_names, devices=devices[:n])
+
+def auto_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto (its default is Explicit), so
+    logical sharding constraints apply inside jit."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 def local_mesh(data: int = 1, model: int = 1, pod: int = 1) -> Mesh:

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -82,12 +81,12 @@ def pipeline_apply(
         # everyone returns; only the last stage's buffer is non-zero -> psum
         return jax.lax.psum(outputs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(stage_params, x)
 
 

@@ -199,14 +199,11 @@ def constrain(x, logical, rules=None):
     mesh = get_abstract_mesh()
     if mesh is None:
         return x
-    try:
-        auto = {
-            name
-            for name, t in zip(mesh.axis_names, mesh.axis_types)
-            if t == jax.sharding.AxisType.Auto
-        }
-    except Exception:
-        auto = set(mesh.axis_names)
+    auto = {
+        name
+        for name, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == jax.sharding.AxisType.Auto
+    }
     if not auto:
         return x
     spec = logical_to_spec(logical, mesh, rules)
@@ -233,20 +230,14 @@ def get_abstract_mesh():
     there: Manual axes must not be constrained).  Under a plain ``with
     mesh:`` context (the pjit path) only the thread-local *physical* mesh is
     populated — fall back to it, with all axes treated as Auto."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return mesh
-    except Exception:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.axis_names:
+        return mesh
+    from jax._src.mesh import thread_resources
 
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and mesh.axis_names:
-            return mesh.abstract_mesh
-    except Exception:
-        pass
+    mesh = thread_resources.env.physical_mesh
+    if mesh.axis_names:
+        return mesh.abstract_mesh
     return None
 
 

@@ -6,9 +6,9 @@ over scenarios (sublanes) x agents (lanes), the whole thing is elementwise
 VPU math — signed disc distance plus the smaller positive root of the
 constant-velocity quadratic ``|p + v t| = r_e + r_a``.
 
-Ego state arrives as per-scenario 1-D blocks broadcast against the agent
-tiles; both grid dimensions are embarrassingly parallel (no cross-tile
-scratch).  Padded agent columns (beyond ``n_valid``) are masked to
+Ego state arrives as per-scenario ``(S, 1)`` columns broadcast against the
+agent tiles (a 1-D block does not match XLA's tiled layout on the chip);
+both grid dimensions are embarrassingly parallel (no cross-tile scratch).  Padded agent columns (beyond ``n_valid``) are masked to
 ``TTC_MAX`` / no-hit so the ops wrapper can pad freely to lane multiples.
 """
 
@@ -21,15 +21,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.collision.ref import TTC_MAX, _EPS
-from repro.kernels.common import tpu_compiler_params
+from repro.kernels.common import default_interpret, tpu_compiler_params
 
 
 def _collision_kernel(
-    ex_ref,  # (Bs,) ego x
-    ey_ref,  # (Bs,)
-    evx_ref,  # (Bs,) ego vel x
-    evy_ref,  # (Bs,)
-    er_ref,  # (Bs,) ego radius
+    ex_ref,  # (Bs, 1) ego x
+    ey_ref,  # (Bs, 1)
+    evx_ref,  # (Bs, 1) ego vel x
+    evy_ref,  # (Bs, 1)
+    er_ref,  # (Bs, 1) ego radius
     ax_ref,  # (Bs, Ba) agent x
     ay_ref,  # (Bs, Ba)
     avx_ref,  # (Bs, Ba)
@@ -44,11 +44,11 @@ def _collision_kernel(
 ):
     j = pl.program_id(1)
 
-    px = ax_ref[...] - ex_ref[...][:, None]
-    py = ay_ref[...] - ey_ref[...][:, None]
-    vx = avx_ref[...] - evx_ref[...][:, None]
-    vy = avy_ref[...] - evy_ref[...][:, None]
-    rad = ar_ref[...] + er_ref[...][:, None]
+    px = ax_ref[...] - ex_ref[...]
+    py = ay_ref[...] - ey_ref[...]
+    vx = avx_ref[...] - evx_ref[...]
+    vy = avy_ref[...] - evy_ref[...]
+    rad = ar_ref[...] + er_ref[...]
 
     c2 = px * px + py * py
     a = vx * vx + vy * vy
@@ -71,25 +71,23 @@ def _collision_kernel(
 
 
 def collision_ttc_fwd(
-    ego_xyvr: tuple[jax.Array, ...],  # 5 x (S,) f32: x, y, vx, vy, r
+    ego_xyvr: tuple[jax.Array, ...],  # 5 x (S, 1) f32: x, y, vx, vy, r
     agent_xyvr: tuple[jax.Array, ...],  # 5 x (S, A) f32: x, y, vx, vy, r
     *,
     n_valid_agents: int,
     block_s: int = 256,
     block_a: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    if interpret is None:
+        interpret = default_interpret()
     S, A = agent_xyvr[0].shape
     bs, ba = min(block_s, S), min(block_a, A)
     assert S % bs == 0 and A % ba == 0, (S, A, bs, ba)
     nS, nA = S // bs, A // ba
 
     kernel = functools.partial(_collision_kernel, ba=ba, n_valid=n_valid_agents)
-    kwargs = {}
-    params = tpu_compiler_params(("parallel", "parallel"))
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
-    ego_spec = pl.BlockSpec((bs,), lambda i, j: (i,))
+    ego_spec = pl.BlockSpec((bs, 1), lambda i, j: (i, 0))
     agent_spec = pl.BlockSpec((bs, ba), lambda i, j: (i, j))
     dist, ttc, hit = pl.pallas_call(
         kernel,
@@ -102,6 +100,6 @@ def collision_ttc_fwd(
             jax.ShapeDtypeStruct((S, A), jnp.int32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=tpu_compiler_params(("parallel", "parallel")),
     )(*ego_xyvr, *agent_xyvr)
     return dist, ttc, hit

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.collision.kernel import collision_ttc_fwd
-from repro.kernels.common import default_interpret
 
 
 def _ceil_to(n: int, m: int) -> int:
@@ -37,15 +36,13 @@ def collision_ttc(
     """Signed distance, TTC and collision flag per ego-agent pair.
 
     Returns ``(dist (S,A) f32, ttc (S,A) f32, hit (S,A) bool)``."""
-    if interpret is None:
-        interpret = default_interpret()
     S, A = agent_radius.shape
     bs = min(block_s, _ceil_to(S, 8))
     ba = min(block_a, _ceil_to(A, 128))
     Sp, Ap = _ceil_to(S, bs), _ceil_to(A, ba)
 
     def pad_ego(x):
-        return jnp.zeros((Sp,), jnp.float32).at[:S].set(x.astype(jnp.float32))
+        return jnp.zeros((Sp, 1), jnp.float32).at[:S, 0].set(x.astype(jnp.float32))
 
     def pad_agent(x):
         return jnp.zeros((Sp, Ap), jnp.float32).at[:S, :A].set(x.astype(jnp.float32))

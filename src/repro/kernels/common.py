@@ -3,28 +3,16 @@
 from __future__ import annotations
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
 
 
 def default_interpret() -> bool:
     """Pallas kernels compile only on TPU; everywhere else run the kernel
-    body in interpret mode (the brief's CPU-validation path)."""
+    body in interpret mode (the CPU validation path)."""
     return jax.default_backend() != "tpu"
 
 
-def tpu_compiler_params(dimension_semantics: tuple[str, ...]):
-    """Best-effort TPU compiler params (ignored in interpret mode).
-
-    The class was renamed across jax releases (``TPUCompilerParams`` →
-    ``CompilerParams``); try both so the semantics actually reach the
-    Mosaic compiler instead of silently degrading to ``None``."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cls is None:
-            return None
-        return cls(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None
+def tpu_compiler_params(dimension_semantics: tuple[str, ...]) -> pltpu.CompilerParams:
+    """Mosaic compiler params naming each grid axis parallel or arbitrary
+    (sequential); interpret mode ignores them."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)

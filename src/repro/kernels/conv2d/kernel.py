@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import default_interpret
+
 
 def _conv_kernel(
     x_ref,  # (1, H+KH-1, W+KW-1, CI) padded input
@@ -51,8 +53,10 @@ def conv2d_fwd(
     out_h: int,
     out_w: int,
     block_co: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
+    if interpret is None:
+        interpret = default_interpret()
     N = x.shape[0]
     KH, KW, CI, CO = w.shape
     bco = min(block_co, CO)

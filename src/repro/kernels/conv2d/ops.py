@@ -7,7 +7,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
 from repro.kernels.conv2d.kernel import conv2d_fwd
 
 
@@ -21,8 +20,6 @@ def conv2d(
     interpret: bool | None = None,
 ) -> jax.Array:
     """SAME convolution, stride 1 (odd kernel sizes)."""
-    if interpret is None:
-        interpret = default_interpret()
     N, H, W, CI = x.shape
     KH, KW, CI2, CO = w.shape
     assert CI == CI2 and KH % 2 == 1 and KW % 2 == 1

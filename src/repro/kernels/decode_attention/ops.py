@@ -60,8 +60,6 @@ def paged_decode_attention(
         return paged_decode_qtok_ref(
             q, k_pages, v_pages, k_new, v_new, block_tables, seq_lens
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, _, Hq, hd = q.shape
     Hkv = k_pages.shape[2]
     G = Hq // Hkv

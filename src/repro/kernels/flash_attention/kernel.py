@@ -103,12 +103,6 @@ def flash_attention_fwd(
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk
     )
-    kwargs = {}
-    # batch / head / q-block axes are embarrassingly parallel; the kv axis
-    # carries the online-softmax scratch and must stay sequential
-    params = tpu_compiler_params(("parallel", "parallel", "parallel", "arbitrary"))
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         kernel,
         grid=(B, Hq, nq, nk),
@@ -127,5 +121,9 @@ def flash_attention_fwd(
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        # batch / head / q-block axes are embarrassingly parallel; the kv
+        # axis carries the online-softmax scratch and must stay sequential
+        compiler_params=tpu_compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary")
+        ),
     )(q, k, v)

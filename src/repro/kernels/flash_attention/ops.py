@@ -12,7 +12,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -28,8 +27,6 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = default_interpret()
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

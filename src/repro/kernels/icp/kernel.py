@@ -20,16 +20,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import default_interpret, tpu_compiler_params
+
 BIG = 3.4e38
 
 
 def _icp_kernel(
     src_ref,  # (Bm, CD)
     tgt_ref,  # (Bn, CD)
-    idx_ref,  # (Bm,) out int32
-    d2_ref,  # (Bm,) out f32
-    best_d_scr,  # (Bm,) f32
-    best_i_scr,  # (Bm,) int32
+    idx_ref,  # (Bm, 1) out int32
+    d2_ref,  # (Bm, 1) out f32
+    best_d_scr,  # (Bm, 1) f32
+    best_i_scr,  # (Bm, 1) int32
     *,
     bn: int,
     n_blocks: int,
@@ -46,16 +48,20 @@ def _icp_kernel(
     t = tgt_ref[...].astype(jnp.float32)  # (Bn, CD)
     s2 = jnp.sum(s * s, axis=1, keepdims=True)  # (Bm, 1)
     t2 = jnp.sum(t * t, axis=1)  # (Bn,)
+    # full f32 passes: at map coordinates (tens of metres) a bf16 pass
+    # misplaces |s|^2 + |t|^2 - 2 s.t by square metres, enough to pick the
+    # wrong neighbour
     cross = jax.lax.dot_general(
-        s, t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        s, t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (Bm, Bn)
     d2 = s2 + t2[None, :] - 2.0 * cross
     # mask padded target rows (beyond n_valid)
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
     d2 = jnp.where(col < n_valid, d2, BIG)
 
-    cand_d = jnp.min(d2, axis=1)
-    cand_i = (j * bn + jnp.argmin(d2, axis=1)).astype(jnp.int32)
+    cand_d = jnp.min(d2, axis=1, keepdims=True)
+    cand_i = (j * bn + jnp.argmin(d2, axis=1, keepdims=True)).astype(jnp.int32)
     better = cand_d < best_d_scr[...]
     best_d_scr[...] = jnp.where(better, cand_d, best_d_scr[...])
     best_i_scr[...] = jnp.where(better, cand_i, best_i_scr[...])
@@ -73,8 +79,13 @@ def icp_correspondences_fwd(
     n_valid_tgt: int,
     block_m: int = 256,
     block_n: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
+    """Per-source-point nearest target: ``(idx (M, 1) int32, d2 (M, 1) f32)``;
+    the outputs are columns because a 1-D block does not match XLA's tiled
+    layout on the chip."""
+    if interpret is None:
+        interpret = default_interpret()
     M, CD = src.shape
     N = tgt.shape[0]
     bm, bn = min(block_m, M), min(block_n, N)
@@ -90,17 +101,18 @@ def icp_correspondences_fwd(
             pl.BlockSpec((bn, CD), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((M,), jnp.int32),
-            jax.ShapeDtypeStruct((M,), jnp.float32),
+            jax.ShapeDtypeStruct((M, 1), jnp.int32),
+            jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bm,), jnp.float32),
-            pltpu.VMEM((bm,), jnp.int32),
+            pltpu.VMEM((bm, 1), jnp.float32),
+            pltpu.VMEM((bm, 1), jnp.int32),
         ],
         interpret=interpret,
+        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
     )(src, tgt)
     return idx, d2

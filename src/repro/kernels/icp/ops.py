@@ -12,9 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
 from repro.kernels.icp.kernel import icp_correspondences_fwd
-from repro.kernels.icp.ref import rigid_transform_ref
+from repro.kernels.icp.ref import icp_update
 
 COORD_PAD = 8  # pad xyz -> 8 lanes for the MXU distance matmul
 
@@ -37,8 +36,6 @@ def icp_correspondences(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Nearest target index + squared distance for every source point."""
-    if interpret is None:
-        interpret = default_interpret()
     M = src.shape[0]
     srcp, _ = _pad_points(src, block_m)
     tgtp, n_tgt = _pad_points(tgt, block_n)
@@ -46,7 +43,7 @@ def icp_correspondences(
         srcp, tgtp, n_valid_tgt=n_tgt, block_m=block_m, block_n=block_n,
         interpret=interpret,
     )
-    return idx[:M], d2[:M]
+    return idx[:M, 0], d2[:M, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -61,12 +58,7 @@ def icp_step(
 
     Returns (R, t, mean_sq_err)."""
     idx, d2 = icp_correspondences(src, tgt, interpret=interpret)
-    matched = tgt[idx]
-    thresh = jnp.quantile(d2, trim_quantile)
-    w = (d2 <= thresh).astype(jnp.float32)
-    R, t = rigid_transform_ref(src, matched, w)
-    err = jnp.sum(d2 * w) / jnp.maximum(w.sum(), 1.0)
-    return R, t, err
+    return icp_update(src, tgt, idx, d2, trim_quantile)
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "interpret"))

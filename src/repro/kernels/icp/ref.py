@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -27,12 +29,35 @@ def rigid_transform_ref(
     w = weights / jnp.maximum(weights.sum(), 1e-9)
     cs = jnp.sum(src * w[:, None], axis=0)
     cm = jnp.sum(matched * w[:, None], axis=0)
-    H = (src - cs).T @ ((matched - cm) * w[:, None])
+    # f32 products throughout: the chip's default matmul pass is bf16
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    H = mm((src - cs).T, (matched - cm) * w[:, None])
     U, _, Vt = jnp.linalg.svd(H)
-    det = jnp.linalg.det(Vt.T @ U.T)
+    det = jnp.linalg.det(mm(Vt.T, U.T))
     S = jnp.diag(jnp.array([1.0, 1.0, 1.0]) * jnp.where(
         jnp.arange(3) == 2, det, 1.0
     ))
-    R = Vt.T @ S @ U.T
-    t = cm - R @ cs
+    R = mm(mm(Vt.T, S), U.T)
+    t = cm - mm(R, cs)
     return R, t
+
+
+def icp_update(
+    src: jax.Array, tgt: jax.Array, idx: jax.Array, d2: jax.Array,
+    trim_quantile: float = 0.9,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Given correspondences, trim outliers and solve the rigid update.
+    Returns (R, t, mean_sq_err) over the kept pairs."""
+    thresh = jnp.quantile(d2, trim_quantile)
+    w = (d2 <= thresh).astype(jnp.float32)
+    R, t = rigid_transform_ref(src, tgt[idx], w)
+    err = jnp.sum(d2 * w) / jnp.maximum(w.sum(), 1.0)
+    return R, t, err
+
+
+@jax.jit
+def icp_step_ref(src: jax.Array, tgt: jax.Array, trim_quantile: float = 0.9):
+    """One ICP iteration with brute-force correspondences (the kernel-free
+    path and the oracle for ``ops.icp_step``)."""
+    idx, d2 = correspondences_ref(src, tgt)
+    return icp_update(src, tgt, idx, d2, trim_quantile)

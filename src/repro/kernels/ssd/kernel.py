@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import default_interpret
+
 
 def _ssd_kernel(
     xdt_ref,  # (1, 1, 1, Q, P)
@@ -82,8 +84,10 @@ def ssd_chunk_scan_fwd(
     Bm: jax.Array,  # (B, G, NC, Q, N)
     Cm: jax.Array,  # (B, G, NC, Q, N)
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
+    if interpret is None:
+        interpret = default_interpret()
     B_, H, NC, Q, P = xdt.shape
     G, N = Bm.shape[1], Bm.shape[4]
     assert H % G == 0

@@ -7,7 +7,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
 from repro.kernels.ssd.kernel import ssd_chunk_scan_fwd
 
 
@@ -22,8 +21,6 @@ def ssd_chunk_scan(
     chunk_size: int = 64,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    if interpret is None:
-        interpret = default_interpret()
     B_, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     Q = min(chunk_size, S)

@@ -27,6 +27,7 @@ from pathlib import Path
 
 from repro.campaign import ArtifactStore, CampaignDriver, qualification_campaign
 from repro.campaign.report import render_report
+from repro.compile_cache import enable_compilation_cache
 from repro.platform import DONE, FaultPlan, Platform
 from repro.platform.chaos import FAIL_DEVICE, KILL_WORKER, STALL_CHECKPOINT
 
@@ -66,6 +67,7 @@ def main(argv=None):
     ap.add_argument("--trace-out", default=None,
                     help="export the span stream (JSONL) to this file")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     with contextlib.ExitStack() as stack:
         root = args.artifacts_dir or stack.enter_context(

@@ -11,6 +11,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import enable_compilation_cache
 from repro.platform import DONE, JobSpec, MapGenJobConfig, Platform
 
 
@@ -25,6 +26,7 @@ def main(argv=None):
     ap.add_argument("--job-devices", type=int, default=4)
     ap.add_argument("--priority", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     spec = JobSpec(
         kind="mapgen",

@@ -7,13 +7,13 @@ chips (data x model over ICI); the multi-pod job adds a leading pod axis
 
 from __future__ import annotations
 
-from repro.distributed.compat import make_mesh
+from repro.distributed.mesh import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def mesh_desc(mesh) -> str:

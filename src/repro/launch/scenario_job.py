@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compile_cache import enable_compilation_cache
 from repro.platform import JobSpec, Platform, ScenarioJobConfig, aggregate_scenario_metrics
 from repro.platform.services import scenario_policies
 from repro.scenario.dsl import FAMILIES
@@ -104,6 +105,7 @@ def main(argv=None):
     ap.add_argument("--ab-test", action="store_true",
                     help="qualify --policy against the deployed baseline")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     platform = Platform(total_devices=args.devices)
     if args.ab_test:

@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import enable_compilation_cache
 from repro.platform import DONE, JobSpec, Platform, ServeJobConfig
 
 
@@ -82,6 +83,7 @@ def main(argv=None):
     ap.add_argument("--job-devices", type=int, default=2)
     ap.add_argument("--priority", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     spec = JobSpec(
         kind="serve",

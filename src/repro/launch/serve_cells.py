@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.launch.cells import serve_cell_plan
 from repro.platform import DONE, JobSpec, Platform, ServeJobConfig
 from repro.serving.cell_router import CellRouter
@@ -130,6 +131,7 @@ def main(argv=None):
     ap.add_argument("--salvage-backoff-s", type=float, default=0.5,
                     help="base delay between salvage rounds (doubles per round)")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     platform = Platform(total_devices=args.pool_devices)
     plan = serve_cell_plan(

@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import enable_compilation_cache
 from repro.platform import DONE, JobSpec, Platform, TrainJobConfig
 
 
@@ -40,6 +41,7 @@ def main(argv=None):
                     help="container size requested for this job")
     ap.add_argument("--priority", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     spec = JobSpec(
         kind="train",

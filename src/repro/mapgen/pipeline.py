@@ -28,6 +28,7 @@ from repro.core.binpipe import stack_batch
 from repro.core.pipeline import Pipeline, Stage
 from repro.core.rdd import ShardedDataset
 from repro.kernels.icp.ops import icp_step
+from repro.kernels.icp.ref import icp_step_ref
 from repro.mapgen import gridmap, slam
 from repro.mapgen.gridmap import GridMap, GridSpec
 
@@ -77,7 +78,8 @@ class MapGenPipeline:
         clouds = data["world"]  # (T, N, 3)
 
         def refine(prev, cur):
-            R, t, err = icp_step(cur, prev, interpret=None if self.cfg.use_pallas_icp else True)
+            step = icp_step if self.cfg.use_pallas_icp else icp_step_ref
+            R, t, err = step(cur, prev)
             return cur @ R.T + t, err
 
         refined_tail, errs = jax.vmap(refine)(clouds[:-1], clouds[1:])

@@ -269,6 +269,10 @@ class Platform:
     def submit(self, spec: JobSpec) -> str:
         """Validate, uniquify, queue; returns the (possibly renamed) job name."""
         spec.validate()  # isolation/grace/elasticity sanity, fail-fast
+        if spec.isolation == "process":
+            from repro.platform import isolation
+
+            isolation.check_backend()
         driver = get_driver(spec.kind)  # raises UnknownServiceKind on typos
         ctx = driver.prepare(spec)  # bad config payloads fail here, not in queue
         with self._cond:

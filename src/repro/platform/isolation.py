@@ -31,6 +31,12 @@ unchanged), stops/resizes/faults requested on the *parent* token are
 relayed at the next checkpoint, and ``token.state`` is refreshed from the
 child's snapshot so resume-after-anything uses the usual driver state.
 
+Only the CPU backend can be split this way: a chip belongs to one process
+at a time, and the supervising process holds it once any tenant has
+touched JAX, so ``Platform.submit`` refuses ``isolation="process"`` on any
+other backend (:func:`check_backend`) instead of starting a child that
+would run on the CPU or hang waiting for the chip.
+
 What threads cannot give, processes do — **enforcement**: a stop (preempt /
 cancel) the child has not honored within ``JobSpec.grace_s`` escalates to
 SIGTERM, then SIGKILL, and the supervisor raises the interruption itself
@@ -103,6 +109,20 @@ def _recv(f):
 # ---------------------------------------------------------------------------
 
 
+def check_backend() -> None:
+    """Refuse process isolation unless this process runs on the CPU."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ValueError(
+            f"isolation='process' needs the cpu backend, but this process "
+            f"runs on {backend!r}: a chip belongs to one process at a time, "
+            "so an isolated worker could not reach it; use "
+            "isolation='thread'"
+        )
+
+
 def _noop_log(msg: str) -> None:
     return None
 
@@ -160,7 +180,6 @@ def run_isolated(
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={container.size}"
     )
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.platform.isolation",

@@ -778,6 +778,8 @@ class ServeDriver:
             from repro.serving.scheduler import Request, token_latencies
 
             seeds = itertools.count(cfg.seed)
+            # replica k runs on the container's k-th device (round robin)
+            devices = itertools.cycle(container.jax_devices())
 
             # observability context bound by the executor (None for bare
             # tokens / tracing-off): serve-stage spans nest under the
@@ -868,6 +870,7 @@ class ServeDriver:
                     spec_k=cfg.spec_k,
                     prefix_cache=cfg.prefix_cache,
                     prefill_chunk=cfg.prefill_chunk,
+                    device=next(devices),
                 )
 
             cell_tier = cfg.cells > 1 or cfg.max_replicas > cfg.replicas
@@ -953,7 +956,9 @@ class ServeDriver:
                         raise NoCellsAlive(
                             f"all {len(router.cells)} serve cells failed and "
                             f"the rebuild budget ({cfg.cell_rebuild_retries}) "
-                            f"is spent; {len(router.stranded)} requests shed"
+                            f"is spent; {len(router.stranded)} requests shed "
+                            f"(last, cell {router.failures[-1][0]}: "
+                            f"{router.failures[-1][1]})"
                         )
                     dead = next(
                         i for i, a in enumerate(router.alive) if not a
@@ -1120,6 +1125,8 @@ class ServeDriver:
                 **deadline_metrics,
                 **{f"replica_{k}": v
                    for k, v in state["router_stats"].items()},
+                # generated token ids per request id, for comparing runs
+                "_request_tokens": {o.rid: list(o.tokens) for o in outs},
             }
 
         from repro.serving.engine import ServeEngine

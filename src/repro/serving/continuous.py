@@ -122,6 +122,7 @@ class ContinuousBatchingEngine:
         spec_ngram: int = 2,
         prefix_cache: bool = False,
         prefill_chunk: int = 0,
+        device: Optional[jax.Device] = None,
     ):
         if cfg.family not in ("dense", "moe"):
             raise ValueError(f"paged serving supports dense/moe, got {cfg.family!r}")
@@ -131,7 +132,10 @@ class ContinuousBatchingEngine:
             raise ValueError("spec_k/prefill_chunk must be >= 0, spec_ngram >= 1")
         self.cfg = cfg
         self.model = model_zoo.build_model(cfg)
-        self.params = params
+        # the replica's chip: params and page pool live there, so every
+        # program of this engine runs there (None: the default device)
+        self.device = device
+        self.params = params if device is None else jax.device_put(params, device)
         self.num_slots = num_slots
         self.page_size = page_size
         self.max_len = max_len
@@ -165,7 +169,10 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------------
     def reset(self) -> None:
         """Fresh pool/queue/slots; compiled programs are retained."""
-        self.pages = self.model.init_paged_state(self.num_pages + 1, self.page_size)
+        with jax.default_device(self.device):
+            self.pages = self.model.init_paged_state(
+                self.num_pages + 1, self.page_size
+            )
         self.alloc = BlockAllocator(
             self.num_slots, self.max_pages_per_seq, self.num_pages
         )

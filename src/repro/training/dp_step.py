@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ModelConfig, TrainConfig
-from repro.distributed.compat import shard_map
 from repro.distributed import collectives
 from repro.models import model_zoo
 from repro.training import optimizer as opt_lib
@@ -99,12 +98,12 @@ def make_dp_train_step(
         return {k: one(k, v) for k, v in batch.items()}
 
     def step_fn(state, batch):
-        smapped = shard_map(
+        smapped = jax.shard_map(
             per_device_step,
             mesh=mesh,
             in_specs=(state_spec, batch_specs(batch)),
             out_specs=(state_spec, state_spec),
-            check=False,
+            check_vma=False,
         )
         return smapped(state, batch)
 

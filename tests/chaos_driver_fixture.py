@@ -23,6 +23,7 @@ class SleeperConfig:
     report_devices: bool = False  # metrics["devices"] = jax.device_count()
     stuck: bool = False  # sleep forever without ever checkpointing
     ignore_sigterm: bool = False  # force the ladder all the way to SIGKILL
+    ready_file: str = ""  # touched once running, after any SIGTERM handler
 
 
 @register_driver
@@ -44,6 +45,8 @@ class SleeperDriver:
             import signal
 
             signal.signal(signal.SIGTERM, signal.SIG_IGN)
+        if cfg.ready_file:
+            open(cfg.ready_file, "w").close()
         devices: Optional[int] = None
         if cfg.report_devices:
             import jax

@@ -94,8 +94,8 @@ def test_psum_and_compressed_reduce_agree():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.distributed import collectives
-from repro.distributed.compat import make_mesh, shard_map
-mesh = make_mesh((8,), ("data",))
+from repro.distributed.mesh import auto_mesh
+mesh = auto_mesh((8,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
 def f(gl):
@@ -104,7 +104,7 @@ def f(gl):
     comp, res = collectives.compressed_psum_mean(tree, collectives.init_residual(tree), ("data",))
     return plain["g"], comp["g"], res["g"]
 
-plain, comp, res = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check=False)(g)
+plain, comp, res = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)(g)
 import numpy as np
 err = float(jnp.max(jnp.abs(plain - comp)))
 scale = float(jnp.max(jnp.abs(plain)))
@@ -123,8 +123,8 @@ def test_hierarchical_equals_flat_psum():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.distributed import collectives
-from repro.distributed.compat import make_mesh, shard_map
-mesh = make_mesh((2, 4), ("pod", "data"))
+from repro.distributed.mesh import auto_mesh
+mesh = auto_mesh((2, 4), ("pod", "data"))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 32))
 
 def f(gl):
@@ -133,7 +133,7 @@ def f(gl):
     hier = collectives.hierarchical_psum_mean(tree, ("data",), ("pod",))
     return flat["g"], hier["g"]
 
-flat, hier = shard_map(f, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(), check=False)(g)
+flat, hier = jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(), check_vma=False)(g)
 import numpy as np
 np.testing.assert_allclose(np.asarray(flat), np.asarray(hier), rtol=1e-6)
 print("OK")
@@ -152,10 +152,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.tiered_store import TieredStore
 from repro.training.checkpoint import CheckpointManager
 
-from repro.distributed.compat import make_mesh
+from repro.distributed.mesh import auto_mesh
 devs = jax.devices()
-mesh4 = make_mesh((4,), ("data",), devices=devs[:4])
-mesh8 = make_mesh((8,), ("data",), devices=devs)
+mesh4 = auto_mesh((4,), ("data",), devices=devs[:4])
+mesh8 = auto_mesh((8,), ("data",), devices=devs)
 x = jnp.arange(64.0).reshape(8, 8)
 x4 = jax.device_put(x, NamedSharding(mesh4, P("data")))
 with tempfile.TemporaryDirectory() as d:

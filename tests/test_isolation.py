@@ -109,17 +109,19 @@ def test_sigkill_mid_chunk_exactly_once_and_bitwise_resume():
         np.testing.assert_array_equal(a, b)
 
 
-def test_enforced_cancel_escalates_to_sigkill(monkeypatch):
+def test_enforced_cancel_escalates_to_sigkill(monkeypatch, tmp_path):
     """A stuck worker (never checkpoints again, ignores SIGTERM) cannot be
     stopped cooperatively; the supervisor enforces the cancel through the
     full SIGTERM -> SIGKILL ladder within the grace window."""
     import threading
 
     monkeypatch.setenv("REPRO_ISOLATION_IMPORT", "chaos_driver_fixture")
+    ready = tmp_path / "ready"
     p = Platform(total_devices=2)
     name = p.submit(JobSpec(
         kind="sleeper", devices=1, isolation="process", grace_s=0.5,
-        config={"stuck": True, "ignore_sigterm": True},
+        config={"stuck": True, "ignore_sigterm": True,
+                "ready_file": str(ready)},
     ))
     # the wait loop drives dispatch, so it must run while we watch for the
     # worker to spawn and then cancel from outside
@@ -130,7 +132,9 @@ def test_enforced_cancel_escalates_to_sigkill(monkeypatch):
     )
     waiter.start()
     deadline = time.monotonic() + 120
-    while not any("isolated worker spawned" in e for e in p.events(name)):
+    # cancel once the worker ignores SIGTERM: a SIGTERM that lands while
+    # the child is still importing ends it before the ladder can escalate
+    while not ready.exists():
         assert time.monotonic() < deadline, p.events(name)
         time.sleep(0.05)
     assert p.cancel(name)
@@ -161,3 +165,17 @@ def test_flaky_process_worker_retries_with_backoff(monkeypatch):
     assert rep.metrics["attempt"] == 3  # state survived both child deaths
     assert sum("resubmitting in" in e for e in rep.events) == 2
     assert len(p.rm.quarantined) == 0  # dead_devices=0: workers, not devices
+
+
+def test_process_isolation_refused_off_cpu(monkeypatch):
+    """On a chip backend the supervising process holds the chip, so an
+    isolated child could never reach it: submit refuses, naming why, and
+    no worker is started."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    p = Platform(total_devices=4)
+    with pytest.raises(ValueError, match="one process at a time"):
+        p.submit(JobSpec(kind="scenario", devices=2, isolation="process",
+                         config=dict(SCN)))
+    assert p.rm.jobs == {}

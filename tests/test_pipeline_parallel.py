@@ -11,12 +11,12 @@ def test_pipeline_forward_and_grad_match_serial():
     run_subprocess(
         """
 import jax, jax.numpy as jnp, numpy as np
-from repro.distributed.compat import make_mesh
+from repro.distributed.mesh import auto_mesh
 from repro.distributed.pipeline_parallel import (
     bubble_fraction, mlp_stage_fn, pipeline_apply, serial_reference)
 
 S, M, mb, d = 4, 6, 2, 16
-mesh = make_mesh((S,), ("stage",))
+mesh = auto_mesh((S,), ("stage",))
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 params = {
     "w1": jax.random.normal(ks[0], (S, d, 32)) * 0.3,

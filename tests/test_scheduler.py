@@ -1,7 +1,11 @@
 """ResourceManager: containers, preemption, elasticity, quarantine (paper §2.3)."""
 
+import jax
+import pytest
+
 from repro.core.scheduler import (
     JOB_PENDING,
+    Container,
     JOB_PREEMPTED,
     JOB_RUNNING,
     Job,
@@ -17,6 +21,16 @@ def test_basic_allocation():
     assert rm.jobs["train"].state == JOB_RUNNING
     assert rm.jobs["sim"].state == JOB_RUNNING
     assert rm.utilization() == 1.0
+
+
+@pytest.mark.parametrize("host", [1, 4])
+def test_container_device_ids_map_onto_jax_devices(monkeypatch, host):
+    """Pool id ``d`` runs on ``jax.devices()[d % count]``: one chip per id
+    on a pool sized to the host, everything on the one device otherwise."""
+    chips = [f"chip{i}" for i in range(host)]
+    monkeypatch.setattr(jax, "devices", lambda: chips)
+    got = Container(cid=0, device_ids=(0, 1, 2, 3, 5)).jax_devices()
+    assert got == [chips[d % host] for d in (0, 1, 2, 3, 5)]
 
 
 def test_isolation_no_overlap():

@@ -458,3 +458,21 @@ def test_fastpath_config_validation(setup):
             cfg, params, num_slots=2, page_size=8, max_len=32,
             spec_k=2, spec_ngram=0,
         )
+
+
+@pytest.mark.parametrize("tier", [{"replicas": 2}, {"cells": 2}])
+def test_compile_refusal_surfaces_in_job_error(monkeypatch, tier):
+    """A program the chip's compiler refuses fails every replica the same
+    way; failover must not bury that cause under "all replicas failed"."""
+    from repro.platform import FAILED, JobSpec, Platform, ServeJobConfig
+
+    def refused(self, now=float("inf")):
+        raise ValueError("Mosaic failed to compile TPU kernel: test refusal")
+
+    monkeypatch.setattr(ContinuousBatchingEngine, "step", refused)
+    p = Platform(total_devices=2)
+    rep = p.wait(p.submit(JobSpec(kind="serve", config=ServeJobConfig(
+        engine="continuous", batch=2, prompt_len=8, gen=2, **tier,
+    ))), timeout_s=120)
+    assert rep.state == FAILED
+    assert "Mosaic failed to compile TPU kernel: test refusal" in rep.error

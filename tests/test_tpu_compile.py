@@ -1,0 +1,101 @@
+"""The Pallas kernels of the main paths compile for a TPU v5e.
+
+Interpret mode on the CPU accepts block shapes and layouts that the chip's
+compiler refuses, so each kernel is compiled here against a described
+``v5e:2x2`` topology (no chip attached) at the widths the entry points use:
+qwen2-0.5b's decode attention (8 slots, 2 KV heads x 7 query heads of width
+64, 16-token pages, bf16), a 2048 x 8 collision sweep, one 512-point ICP
+step and a 512-token flash-attention block.  The topology is described
+inside a fixture, never at import, so every test worker collects the same
+tests and only the worker that runs them loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.collision.ops import collision_ttc
+from repro.kernels.decode_attention.kernel import (
+    paged_decode_fwd,
+    paged_decode_qtok_fwd,
+)
+from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.icp.ops import icp_step
+
+B, HKV, G, HD, PAGE, N_PAGES, Q = 8, 2, 7, 64, 16, 10, 8
+P = B * N_PAGES + 1  # pool pages, null page last
+BF = jnp.bfloat16
+I32 = jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip's compiles cannot be read back from the persistent
+    # cache without a chip, so keep them out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _paged(one):
+    s = lambda shape, dt=BF: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    fn = lambda *a: paged_decode_fwd(*a, interpret=False)  # noqa: E731
+    return fn, (
+        s((B, HKV, G, HD)), s((P, PAGE, HKV, HD)), s((P, PAGE, HKV, HD)),
+        s((B, HKV, HD)), s((B, HKV, HD)), s((B, N_PAGES), I32), s((B,), I32),
+    )
+
+
+def _paged_qtok(one):
+    s = lambda shape, dt=BF: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    fn = lambda *a: paged_decode_qtok_fwd(*a, group=G, interpret=False)  # noqa: E731
+    return fn, (
+        s((B, HKV, Q * G, HD)), s((P, PAGE, HKV, HD)), s((P, PAGE, HKV, HD)),
+        s((B, Q, HKV, HD)), s((B, Q, HKV, HD)), s((B, N_PAGES), I32), s((B,), I32),
+    )
+
+
+def _collision(one):
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
+    S, A = 2048, 8
+    fn = lambda *a: collision_ttc(*a, interpret=False)  # noqa: E731
+    return fn, (s((S, 2)), s((S, 2)), s((S,)), s((S, A, 2)), s((S, A, 2)), s((S, A)))
+
+
+def _icp(one):
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
+    return (lambda a, b: icp_step(a, b, interpret=False)), (s((512, 3)), s((512, 3)))
+
+
+def _flash(one):
+    s = lambda shape: jax.ShapeDtypeStruct(shape, BF, sharding=one)  # noqa: E731
+    fn = lambda *a: flash_attention_fwd(*a, interpret=False)  # noqa: E731
+    return fn, (s((1, 14, 512, 64)), s((1, 2, 512, 64)), s((1, 2, 512, 64)))
+
+
+@pytest.mark.parametrize(
+    "build", [_paged, _paged_qtok, _collision, _icp, _flash],
+    ids=["paged_decode_fwd", "paged_decode_qtok_fwd", "collision_ttc",
+         "icp_step", "flash_attention_fwd"],
+)
+def test_kernel_compiles_for_v5e(one_chip, build):
+    fn, args = build(one_chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
