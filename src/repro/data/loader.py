@@ -15,6 +15,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from repro.core.rdd import ShardedDataset
+from repro.obs import annotate
 
 
 class BatchLoader:
@@ -90,7 +91,8 @@ class BatchLoader:
         self._thread = threading.Thread(target=self._producer, args=(epochs,), daemon=True)
         self._thread.start()
         while True:
-            item = self._queue.get()
+            with annotate("repro.loader.wait"):
+                item = self._queue.get()
             if item is None:
                 return
             yield item

@@ -144,8 +144,9 @@ def param_logical(model):
 def loss_fn(model, params, batch) -> tuple[jax.Array, dict]:
     cfg = model.cfg
     logits, moe_metrics = model.forward(params, batch)
-    mask = losses.loss_mask_for(cfg, batch)
-    loss, metrics = losses.ce_loss(cfg, logits, batch["targets"], mask)
+    with jax.named_scope("lm_head"):
+        mask = losses.loss_mask_for(cfg, batch)
+        loss, metrics = losses.ce_loss(cfg, logits, batch["targets"], mask)
     if cfg.moe is not None:
         loss = loss + cfg.moe.router_aux_coef * moe_metrics.aux_loss
         loss = loss + cfg.moe.router_z_coef * moe_metrics.router_z_loss

@@ -82,34 +82,38 @@ def block_apply(
       avoids GSPMD's replicate-repartition at the ys boundary.
     * paged decode: ``paged`` is (k_pages, v_pages, block_tables, seq_lens)
       for this layer; same read-only contract through the paged kernel.
-    """
-    h = layers.apply_norm(cfg, p["ln1"], x)
-    q, k, v = attention.qkv(cfg, p["attn"], h, angles)
-    if paged is not None:
-        kp, vp, block_tables, seq_lens = paged
-        o = attention.paged_decode(
-            q, kp, vp, k, v, block_tables=block_tables, seq_lens=seq_lens
-        )
-        kv_out = (k, v)
-    elif cache is not None:
-        ck, cv = cache
-        o = attention.sdpa_decode_readonly(
-            q, ck, cv, k, v, q_pos=q_pos, kv_pos=kv_pos,
-            scores_dtype=jnp.bfloat16 if cfg.attn_scores_bf16 else jnp.float32,
-        )
-        kv_out = (k, v)
-    else:
-        o = attention.attend(cfg, q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=True)
-        kv_out = (k, v)
-    x = x + attention.out_proj(cfg, p["attn"], o)
 
-    h2 = layers.apply_norm(cfg, p["ln2"], x)
-    if "moe" in p:
-        y, metrics = moe.apply_moe(cfg, p["moe"], h2)
-    else:
-        y = mlp.apply_mlp(cfg, p["mlp"], h2)
-        metrics = _zero_metrics()
-    x = x + y
+    The ``attention`` and ``mlp`` named scopes ride into every device op's
+    HLO ``op_name``, forward, recomputed and backward alike, so a profile
+    buckets the chip's time by layer.
+    """
+    with jax.named_scope("attention"):
+        h = layers.apply_norm(cfg, p["ln1"], x)
+        q, k, v = attention.qkv(cfg, p["attn"], h, angles)
+        if paged is not None:
+            kp, vp, block_tables, seq_lens = paged
+            o = attention.paged_decode(
+                q, kp, vp, k, v, block_tables=block_tables, seq_lens=seq_lens
+            )
+        elif cache is not None:
+            ck, cv = cache
+            o = attention.sdpa_decode_readonly(
+                q, ck, cv, k, v, q_pos=q_pos, kv_pos=kv_pos,
+                scores_dtype=jnp.bfloat16 if cfg.attn_scores_bf16 else jnp.float32,
+            )
+        else:
+            o = attention.attend(cfg, q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=True)
+        kv_out = (k, v)
+        x = x + attention.out_proj(cfg, p["attn"], o)
+
+    with jax.named_scope("mlp"):
+        h2 = layers.apply_norm(cfg, p["ln2"], x)
+        if "moe" in p:
+            y, metrics = moe.apply_moe(cfg, p["moe"], h2)
+        else:
+            y = mlp.apply_mlp(cfg, p["mlp"], h2)
+            metrics = _zero_metrics()
+        x = x + y
     x = constrain(x, ("batch", "seq", "act_embed"))
     return x, kv_out, metrics
 
@@ -135,7 +139,8 @@ def run_layers_train(cfg: ModelConfig, stacked: Any, x: jax.Array, angles, q_pos
         return h, metrics
 
     body = _maybe_remat(body, cfg)
-    x, metrics = scan_or_unroll(body, x, stacked, cfg.scan_layers)
+    with jax.named_scope("layers"):
+        x, metrics = scan_or_unroll(body, x, stacked, cfg.scan_layers)
     return x, jax.tree.map(jnp.mean, metrics)
 
 
@@ -221,10 +226,11 @@ class TransformerLM:
     def _embed(self, params, batch) -> tuple[jax.Array, Optional[jax.Array]]:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        x = layers.embed_tokens(params["embed"], batch["tokens"], dtype)
-        if cfg.frontend == "vision_patches" and "patches" in batch:
-            pe = layers.apply_linear(params["patch_proj"], batch["patches"].astype(dtype))
-            x = jnp.concatenate([pe, x], axis=1)
+        with jax.named_scope("embed"):
+            x = layers.embed_tokens(params["embed"], batch["tokens"], dtype)
+            if cfg.frontend == "vision_patches" and "patches" in batch:
+                pe = layers.apply_linear(params["patch_proj"], batch["patches"].astype(dtype))
+                x = jnp.concatenate([pe, x], axis=1)
         x = constrain(x, ("batch", "seq", "act_embed"))
         return x
 
@@ -245,8 +251,9 @@ class TransformerLM:
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         angles = self._angles(batch, pos)
         x, metrics = run_layers_train(cfg, params["layers"], x, angles, pos, pos)
-        x = layers.apply_norm(cfg, params["final_norm"], x)
-        logits = layers.lm_logits(params["embed"], x, cfg.tie_embeddings)
+        with jax.named_scope("lm_head"):
+            x = layers.apply_norm(cfg, params["final_norm"], x)
+            logits = layers.lm_logits(params["embed"], x, cfg.tie_embeddings)
         logits = constrain(logits, ("batch", "seq", "vocab_act"))
         return logits, metrics
 

@@ -13,7 +13,7 @@ controller, serving routers — can emit telemetry without import cycles:
   (Perfetto-loadable), and a per-stage p50/p99 text report.
 """
 
-from repro.obs.trace import CHILD_SPAN_BASE, Span, Tracer
+from repro.obs.trace import CHILD_SPAN_BASE, Span, Tracer, annotate
 from repro.obs.metrics import MetricsRegistry, stage_summary
 from repro.obs.export import (
     read_jsonl,
@@ -27,6 +27,7 @@ __all__ = [
     "CHILD_SPAN_BASE",
     "Span",
     "Tracer",
+    "annotate",
     "MetricsRegistry",
     "stage_summary",
     "read_jsonl",

@@ -17,6 +17,10 @@ and clock origin into the bootstrap frame; the child builds its own
 tracer with ``seq0=CHILD_SPAN_BASE`` so its span ids can never collide
 with parent-side ids for the same (job, attempt), and ships its span
 dicts back on the terminal IPC frame for ``merge()``.
+
+``annotate`` is the other kind of span: a host span written into the JAX
+profiler's own trace, so it lands on the device trace's clock.  It is the
+program's one way to put a host span beside the device's operations.
 """
 
 from __future__ import annotations
@@ -32,6 +36,16 @@ from typing import Any, Callable, Iterable, Optional
 # child-side spans for the same (job, attempt).  Fixed, so numbering
 # stays deterministic.
 CHILD_SPAN_BASE = 1 << 20
+
+
+def annotate(name: str):
+    """Context manager: a host span ``name`` in the JAX profiler's trace
+    (``jax.profiler.TraceAnnotation``), on the clock of the device
+    operations.  About a microsecond when no profile runs.  jax is imported
+    here, not at module import, so ``repro.obs`` stays stdlib-only."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 @dataclasses.dataclass

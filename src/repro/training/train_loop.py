@@ -3,6 +3,12 @@
 ``make_train_step`` builds a jitted SPMD ``(state, batch) -> (state, metrics)``
 whose in/out shardings come from the logical rule tables, so the same factory
 serves the 1-device test mesh, the 16x16 pod and the 2x16x16 multi-pod mesh.
+
+The step's layers carry ``jax.named_scope`` names (``embed``, ``attention``,
+``mlp``, ``lm_head`` in the model; ``layers``, ``microbatches``,
+``grad_accum``, ``optimizer`` here and in the layer runner).  They change
+only each device op's HLO ``op_name`` metadata, so a profile can bucket the
+chip's time by layer.
 """
 
 from __future__ import annotations
@@ -85,16 +91,19 @@ def make_loss_and_grad(model, num_microbatches: int):
         def body(acc, mb_batch):
             (loss, metrics), grads = vg(params, mb_batch)
             acc_g, acc_l = acc
-            acc_g = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32) / num_microbatches, acc_g, grads
-            )
+            with jax.named_scope("grad_accum"):
+                acc_g = jax.tree.map(
+                    lambda a, g: a + g.astype(jnp.float32) / num_microbatches, acc_g, grads
+                )
             return (acc_g, acc_l + loss / num_microbatches), metrics
 
-        zero_g = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)
+        with jax.named_scope("grad_accum"):
+            zero_g = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)
         # unrolled when cfg.scan_layers=False (roofline lowers need exact costs)
-        (grads, loss), metrics = scan_or_unroll(
-            body, (zero_g, 0.0), mb, model.cfg.scan_layers
-        )
+        with jax.named_scope("microbatches"):
+            (grads, loss), metrics = scan_or_unroll(
+                body, (zero_g, 0.0), mb, model.cfg.scan_layers
+            )
         metrics = jax.tree.map(lambda m: jnp.mean(m), metrics)
         return loss, metrics, grads
 
@@ -135,8 +144,9 @@ def make_train_step(
     def train_step(state, batch):
         params, opt_state, step = state["params"], state["opt"], state["step"]
         loss, metrics, grads = grads_fn(params, batch)
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-        new_params, new_opt = optimizer.update(grads, opt_state, params, step)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+            new_params, new_opt = optimizer.update(grads, opt_state, params, step)
         # re-constrain updated trees to their target shardings
         new_params = jax.tree.map(
             lambda x, s: jax.lax.with_sharding_constraint(x, s),
