@@ -7,7 +7,9 @@ One chip, in one process (a chip belongs to one process at a time):
 
   kernels   the Pallas kernels against their references at the widths the
             entry points use (paged decode Q=1 and Q=8 at qwen2-0.5b's
-            widths in bf16, collision/TTC over 2048x8, one ICP step);
+            widths in bf16, flash attention's output and q/k/v gradients
+            at the train cell's 2 x 2048 tokens and qwen2-0.5b's heads,
+            collision/TTC over 2048x8, one ICP step);
   serve     qwen2-0.5b at its published widths (random weights) as a
             ``serve`` job: Platform -> ServeDriver -> ContinuousBatchingEngine,
             8 requests x 128 prompt tokens x 32 greedy tokens, then again with
@@ -66,6 +68,7 @@ def phase_kernels() -> None:
     from repro.kernels.collision.ops import collision_ttc
     from repro.kernels.collision.ref import TTC_MAX, collision_ttc_ref
     from repro.kernels.decode_attention.ops import paged_decode_attention
+    from repro.kernels.flash_attention.ops import flash_attention, flash_attention_reference
     from repro.kernels.icp.ops import icp_step
     from repro.kernels.icp.ref import icp_step_ref
 
@@ -96,6 +99,26 @@ def phase_kernels() -> None:
             raise AssertionError(f"paged decode Q={Q}: max|kernel-ref| {diff} > {tol}")
         log(f"[kernels] paged_decode Q={Q} bf16 B={B} Hq={Hq} Hkv={Hkv} hd={hd} "
             f"page={page}: max|kernel-ref|={diff:.3g} (tol {tol}) PASS")
+
+    # the train cell's attention: 2 x 2048 tokens, causal, forward and backward
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    S = 2048
+    q = jax.random.normal(ks[0], (2, S, Hq, hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, S, Hkv, hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, S, Hkv, hd), jnp.bfloat16)
+    ct = jax.random.normal(ks[3], (2, S, Hq, hd), jnp.bfloat16)
+    out, vjp = jax.vjp(jax.jit(flash_attention), q, k, v)
+    ref, vjp_ref = jax.vjp(flash_attention_reference, q, k, v)
+    # bf16 operands on the MXU with f32 accumulation against an f32 oracle
+    # rounded to bf16: gaps of a few bf16 ulps of the largest entry
+    tol = 2e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv"), (out, *vjp(ct)), (ref, *vjp_ref(ct))):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        gap = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        if not gap <= tol:
+            raise AssertionError(f"flash attention {name}: max|kernel-ref|/max|ref| {gap} > {tol}")
+        log(f"[kernels] flash_attention {name} bf16 B=2 S={S} Hq={Hq} Hkv={Hkv} hd={hd} "
+            f"causal: max|kernel-ref|/max|ref|={gap:.3g} (tol {tol}) PASS")
 
     S, A = 2048, 8
     ks = jax.random.split(jax.random.PRNGKey(7), 6)

@@ -115,7 +115,10 @@ class ModelConfig:
     # runtime knobs (overridable per run)
     remat: str = "dots"  # none | dots | full
     scan_layers: bool = True
-    attention_impl: str = "einsum"  # einsum (GSPMD path) | blocked | flash | hd_sharded
+    # flash: the flash kernel where attention.attend sees it applies (full
+    # self-attention on one TPU), einsum elsewhere | einsum (GSPMD path) |
+    # blocked | hd_sharded
+    attention_impl: str = "flash"
     attn_scores_bf16: bool = False  # halve attention-score traffic (flagged numerics)
 
     # ------------------------------------------------------------------

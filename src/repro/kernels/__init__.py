@@ -6,7 +6,8 @@ BlockSpec VMEM tiling, a jitted wrapper (ops.py) and a pure-jnp oracle
 (ref.py).  Kernels run `interpret=True` on CPU (validation) and compiled on
 TPU (the target).
 
-  flash_attention/  -- online-softmax tiled attention (LM training hot spot)
+  flash_attention/  -- causal/full attention with a backward: JAX's splash
+                       kernels vmapped over KV heads (LM training hot spot)
   decode_attention/ -- paged GQA decode attention over block tables (serving)
   ssd/              -- Mamba-2 SSD chunk scan (SSM archs)
   icp/              -- ICP nearest-neighbor correspondence (HD map generation)

@@ -10,7 +10,7 @@ kernel computes, entirely in registers/VMEM:
   state update: state' = state·exp(Σ dA) + (x·dt)^T @ (B ⊙ decay_tail)
 
 B/C group tensors are shared across the heads of a group via the BlockSpec
-index_map (h -> h * G // H), mirroring the GQA trick in flash_attention.
+index_map (h -> h * G // H), as a GQA kernel shares a KV head.
 """
 
 from __future__ import annotations

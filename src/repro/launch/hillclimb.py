@@ -33,10 +33,6 @@ def _einsum_attention(model_cfg, pcfg):
     return dataclasses.replace(model_cfg, attention_impl="einsum"), pcfg
 
 
-def _flash_attention(model_cfg, pcfg):
-    return dataclasses.replace(model_cfg, attention_impl="flash"), pcfg
-
-
 def _no_remat(model_cfg, pcfg):
     return dataclasses.replace(model_cfg, remat="none"), pcfg
 
@@ -89,7 +85,8 @@ def _ssd_chunk(n):
 VARIANTS = {
     "baseline": ("policy defaults", lambda m, p: (m, p), None),
     "blocked_attn": ("q-block chunked attention (never materialize SxS)", _blocked_attention, None),
-    "einsum_attn": ("full einsum attention", _einsum_attention, None),
+    "einsum_attn": ("einsum attention everywhere (the default takes the flash kernel "
+                    "for full self-attention on one TPU)", _einsum_attention, None),
     "no_remat": ("disable activation recompute", _no_remat, None),
     "remat_full": ("remat everything", _remat_full, None),
     "mb1": ("single microbatch", _mb(1), None),

@@ -1,9 +1,11 @@
 """Grouped-query attention: one implementation for train / prefill / decode.
 
-The default implementation is einsum-based (GSPMD-friendly; non-divisible
-head counts are padded by the partitioner).  ``attention_impl='flash'``
-routes the core through the Pallas flash-attention kernel for divisible,
-power-of-two shapes (training hot path).
+The default ``attention_impl='flash'`` sends full-sequence self-attention
+on one TPU through the flash kernel (``kernels.flash_attention``: forward
+and backward, scores kept in VMEM) where :func:`attend` can see that it
+applies, and every other call through the einsum core :func:`sdpa`
+(GSPMD-friendly; non-divisible head counts are padded by the partitioner).
+``'einsum'``, ``'blocked'`` and ``'hd_sharded'`` pick their core always.
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, get_abstract_mesh
 from repro.models import layers
 from repro.models.params import ParamDef
 
@@ -237,10 +239,24 @@ def blocked_sdpa(
 
 
 def flash_sdpa(q, k, v, *, q_pos, kv_pos, causal=True):
-    """Pallas flash-attention path (training shapes; full self-attention)."""
+    """Pallas flash-attention path (full self-attention, positions 0..S-1)."""
     from repro.kernels.flash_attention import ops as fa_ops
 
     return fa_ops.flash_attention(q, k, v, causal=causal)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_applies(q: jax.Array, k: jax.Array) -> bool:
+    """Self-attention with no cache, a length the kernel tiles, on one TPU:
+    a ``pallas_call`` is not partitioned by GSPMD, so a mesh of several
+    devices keeps the einsum core."""
+    if q.shape[1] != k.shape[1] or q.shape[1] % 128 != 0 or not _on_tpu():
+        return False
+    mesh = get_abstract_mesh()
+    return mesh is None or mesh.size == 1
 
 
 def attend(
@@ -253,11 +269,17 @@ def attend(
     kv_pos,
     causal=True,
 ) -> jax.Array:
-    if (
-        cfg.attention_impl == "flash"
-        and q.shape[1] == k.shape[1]  # self-attention, no cache
-        and q.shape[1] % 128 == 0
-    ):
+    """Attention core of a train or prefill call: q (B,S,Hq,hd), k/v
+    (B,T,Hkv,hd) -> (B,S,Hq,hd).
+
+    Under ``attention_impl='flash'`` (the default) a call goes to the flash
+    kernel when its input shows that the kernel applies (``_flash_applies``:
+    S == T, S a multiple of 128, a TPU backend, no mesh of several devices)
+    and to :func:`sdpa` otherwise: decode, short or odd prefill buckets, the
+    CPU, sharded meshes.  The kernel masks by index and not by position:
+    a causal call passes ``q_pos == kv_pos == arange(S)`` per row, and a
+    non-causal one attends to every key, as every caller does."""
+    if cfg.attention_impl == "flash" and _flash_applies(q, k):
         return flash_sdpa(q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=causal)
     if cfg.attention_impl == "blocked" and q.shape[1] > 1024:
         return blocked_sdpa(

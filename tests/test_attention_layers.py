@@ -57,6 +57,87 @@ def test_flash_equals_sdpa_inside_model_path():
     np.testing.assert_allclose(np.asarray(fl), np.asarray(full), atol=2e-5, rtol=2e-5)
 
 
+@pytest.fixture
+def routes(monkeypatch):
+    """``attend`` on a TPU backend, each core replaced by a recorder."""
+    seen = []
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "flash_sdpa", lambda q, *a, **kw: seen.append("flash") or q)
+    monkeypatch.setattr(attention, "sdpa", lambda q, *a, **kw: seen.append("sdpa") or q)
+    return seen
+
+
+def _attend(cfg, S, T=None):
+    q, k, v = _qkv(jax.random.PRNGKey(8), B=1, S=S)
+    if T is not None:
+        k, v = k[:, :T], v[:, :T]
+    pos = jnp.broadcast_to(jnp.arange(S), (1, S))
+    kv_pos = jnp.broadcast_to(jnp.arange(k.shape[1]), (1, k.shape[1]))
+    return attention.attend(cfg, q, k, v, q_pos=pos, kv_pos=kv_pos, causal=True)
+
+
+def test_flash_route_takes_full_self_attention_on_one_tpu(routes):
+    assert _cfg().attention_impl == "flash"
+    _attend(_cfg(), 128)
+    _attend(_cfg(), 256)
+    assert routes == ["flash", "flash"]
+
+
+@pytest.mark.parametrize("case", ["decode", "prefill_96", "two_device_mesh", "einsum", "cpu"])
+def test_other_calls_reach_the_einsum_core(routes, monkeypatch, case):
+    cfg = _cfg()
+    if case == "decode":
+        _attend(cfg, 1, T=64)
+    elif case == "prefill_96":
+        _attend(cfg, 96)
+    elif case == "two_device_mesh":
+        mesh = jax.sharding.AbstractMesh(
+            (2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+        with jax.sharding.use_abstract_mesh(mesh):
+            _attend(cfg, 128)
+    elif case == "einsum":
+        _attend(dataclasses.replace(cfg, attention_impl="einsum"), 128)
+    else:
+        monkeypatch.setattr(attention, "_on_tpu", lambda: False)
+        _attend(cfg, 128)
+    assert routes == ["sdpa"]
+
+
+def test_train_loss_and_grads_through_the_flash_route(monkeypatch):
+    """A tiny model's microbatched loss and gradients with the kernel forced
+    on (interpret mode) match the einsum route."""
+    from repro.models import model_zoo
+    from repro.training.train_loop import make_loss_and_grad
+
+    cfg = _cfg(num_layers=2, remat="dots")
+    model = model_zoo.build_model(cfg)
+    params = jax.tree.map(lambda p: p.astype(jnp.float32),  # f32 grads, not bf16
+                          model_zoo.init_params(model, jax.random.PRNGKey(9)))
+    toks = jax.random.randint(jax.random.PRNGKey(10), (4, 129), 0, cfg.vocab_size)
+    batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
+
+    def run(impl):
+        m = model_zoo.build_model(dataclasses.replace(cfg, attention_impl=impl))
+        loss, _, grads = jax.jit(make_loss_and_grad(m, 2))(params, batch)
+        return loss, grads
+
+    ref_loss, ref_grads = run("einsum")
+    flash_calls = []
+    real = attention.flash_sdpa
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "flash_sdpa",
+                        lambda *a, **kw: flash_calls.append(1) or real(*a, **kw))
+    loss, grads = run("flash")
+    assert flash_calls  # the route engaged
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        r = ref_grads
+        for key in path:
+            r = r[key.key]
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-5, rtol=1e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 def test_causality():
     """Changing a future token never changes a past output."""
     q, k, v = _qkv(jax.random.PRNGKey(2), S=32)

@@ -7,11 +7,16 @@ import pytest
 
 from repro.kernels.conv2d.ops import conv2d
 from repro.kernels.conv2d.ref import conv2d_ref
-from repro.kernels.flash_attention.ops import flash_attention, flash_attention_reference
+from repro.kernels.flash_attention.ops import (
+    block_size,
+    flash_attention,
+    flash_attention_reference,
+)
 from repro.kernels.icp.ops import icp_align, icp_correspondences
 from repro.kernels.icp.ref import correspondences_ref, rigid_transform_ref
 from repro.kernels.ssd.ops import ssd_chunk_scan
 from repro.kernels.ssd.ref import ssd_sequential_ref
+from repro.models.attention import sdpa
 
 
 # ---------------------------------------------------------------------------
@@ -44,14 +49,54 @@ def test_flash_attention_matches_ref(B, S, Hq, Hkv, D, causal, dtype):
 
 
 def test_flash_attention_block_sizes():
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (1, 256, 4, 64))
-    k = jax.random.normal(ks[1], (1, 256, 4, 64))
-    v = jax.random.normal(ks[2], (1, 256, 4, 64))
-    ref = flash_attention_reference(q, k, v, causal=True)
-    for bq, bk in [(64, 64), (128, 256), (256, 64)]:
-        out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    """Each length tiles with the largest of 512, 256 and 128 that divides it."""
+    for S, block in [(384, 128), (256, 256), (1024, 512)]:
+        assert block_size(S) == block
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        q = jax.random.normal(ks[0], (1, S, 2, 64))
+        k = jax.random.normal(ks[1], (1, S, 2, 64))
+        v = jax.random.normal(ks[2], (1, S, 2, 64))
+        ref = flash_attention_reference(q, k, v, causal=True)
+        out = flash_attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def test_flash_attention_kernel_is_reused_across_traces():
+    """The kernel a first trace builds serves later traces of the shape:
+    its cached mask arrays are constants, not the first trace's tracers."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    q = jax.random.normal(ks[0], (1, 128, 2, 64))
+    k = jax.random.normal(ks[1], (1, 128, 1, 64))
+    first = jax.jit(lambda q, k: flash_attention(q, k, k))(q, k)
+    again = jax.jit(lambda q, k: 2.0 * flash_attention(q, k, k))(q, k)
+    np.testing.assert_allclose(np.asarray(again), 2.0 * np.asarray(first), rtol=1e-6)
+
+
+@pytest.mark.parametrize("S", [128, 256])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("G", [1, 7])
+def test_flash_attention_grads_match_sdpa(G, D, causal, S):
+    """Output and q/k/v gradients of the flash path against the einsum core."""
+    B, Hkv = 1, 2
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q = jax.random.normal(ks[0], (B, S, Hkv * G, D))
+    k = jax.random.normal(ks[1], (B, S, Hkv, D))
+    v = jax.random.normal(ks[2], (B, S, Hkv, D))
+    ct = jax.random.normal(ks[3], q.shape)  # a cotangent that weighs every output
+    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal)
+
+    def einsum(q, k, v):
+        return sdpa(q, k, v, q_pos=pos, kv_pos=pos, causal=causal)
+
+    (o1, vjp1), (o2, vjp2) = jax.vjp(flash, q, k, v), jax.vjp(einsum, q, k, v)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5, rtol=2e-5)
+    for name, g1, g2 in zip("qkv", vjp1(ct), vjp2(ct)):
+        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-4, rtol=1e-4,
+                                   err_msg=f"d{name}")
 
 
 # ---------------------------------------------------------------------------

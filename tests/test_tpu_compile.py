@@ -5,24 +5,30 @@ compiler refuses, so each kernel is compiled here against a described
 ``v5e:2x2`` topology (no chip attached) at the widths the entry points use:
 qwen2-0.5b's decode attention (8 slots, 2 KV heads x 7 query heads of width
 64, 16-token pages, bf16), a 2048 x 8 collision sweep, one 512-point ICP
-step and a 512-token flash-attention block.  The topology is described
-inside a fixture, never at import, so every test worker collects the same
-tests and only the worker that runs them loads the TPU compiler.
+step, and the flash attention's forward and backward at the train cell's
+2 x 2048 tokens (qwen2-0.5b's 14/2 heads of width 64, qwen3-4b's 32/8 of
+width 128).  A two-layer train step at qwen2-0.5b's widths checks that the
+route puts the kernel in the step and no score matrix.  The topology is
+described inside a fixture, never at import, so every test worker collects
+the same tests and only the worker that runs them loads the TPU compiler.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels.collision.ops import collision_ttc
 from repro.kernels.decode_attention.kernel import (
     paged_decode_fwd,
     paged_decode_qtok_fwd,
 )
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.icp.ops import icp_step
 
 B, HKV, G, HD, PAGE, N_PAGES, Q = 8, 2, 7, 64, 16, 10, 8
@@ -84,18 +90,52 @@ def _icp(one):
     return (lambda a, b: icp_step(a, b, interpret=False)), (s((512, 3)), s((512, 3)))
 
 
-def _flash(one):
-    s = lambda shape: jax.ShapeDtypeStruct(shape, BF, sharding=one)  # noqa: E731
-    fn = lambda *a: flash_attention_fwd(*a, interpret=False)  # noqa: E731
-    return fn, (s((1, 14, 512, 64)), s((1, 2, 512, 64)), s((1, 2, 512, 64)))
+def _flash(n_heads, n_kv, hd):
+    def build(one):
+        s = lambda shape: jax.ShapeDtypeStruct(shape, BF, sharding=one)  # noqa: E731
+
+        def loss(q, k, v):
+            out = fa_ops.flash_attention(q, k, v, causal=True, interpret=False)
+            return jnp.sum(out.astype(jnp.float32))
+
+        fn = jax.grad(loss, argnums=(0, 1, 2))
+        return fn, (s((2, 2048, n_heads, hd)), s((2, 2048, n_kv, hd)), s((2, 2048, n_kv, hd)))
+
+    return build
 
 
 @pytest.mark.parametrize(
-    "build", [_paged, _paged_qtok, _collision, _icp, _flash],
+    "build", [_paged, _paged_qtok, _collision, _icp, _flash(14, 2, 64), _flash(32, 8, 128)],
     ids=["paged_decode_fwd", "paged_decode_qtok_fwd", "collision_ttc",
-         "icp_step", "flash_attention_fwd"],
+         "icp_step", "flash_attention_grad_qwen2_0_5b", "flash_attention_grad_qwen3_4b"],
 )
 def test_kernel_compiles_for_v5e(one_chip, build):
     fn, args = build(one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_train_step_takes_the_flash_kernel_for_v5e(one_chip, monkeypatch):
+    """Two layers of qwen2-0.5b at 2 x 2048 tokens: the step holds the
+    kernel's forward, dq and dkv calls and no f32 score matrix."""
+    from repro.config import ParallelConfig, TrainConfig, get_arch
+    from repro.models import attention
+    from repro.training.train_loop import make_train_step, state_shardings
+
+    # the described chip's backend is not the process's: steer the route
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fa_ops, "default_interpret", lambda: False)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1), ("data", "model"))
+    cfg = dataclasses.replace(get_arch("qwen2-0.5b"), num_layers=2)
+    bundle = make_train_step(cfg, TrainConfig(), ParallelConfig(num_microbatches=1), mesh)
+    state = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0)), state_shardings(bundle, mesh))
+    tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec()))
+    with mesh:
+        text = jax.jit(bundle.train_step).lower(
+            state, {"tokens": tok, "targets": tok}).compile().as_text()
+    kernels = set(re.findall(r"%(splash_mqa_\w+?)(?:\.\d+)? = ", text))
+    assert kernels == {"splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                       "splash_mqa_dkv_no_residuals"}
+    assert not re.search(r"f32\[[\d,]*2048,2048\]", text)
