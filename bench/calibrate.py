@@ -57,7 +57,7 @@ def main(argv=None) -> int:
                 out["control_gap"] = ctrl
         else:
             rec = train.run(ctx)
-            ref = train.reference_readings(ctx.config, ctx.mix, seed)
+            ref = train.reference_readings(ctx)
 
             def judged(readings):
                 c = train.compare(readings, ref)
@@ -69,10 +69,8 @@ def main(argv=None) -> int:
             out["loss"] = rec.extra["program"]["loss"]
             out["ref_loss"] = ref["loss"]
             if seed in controls:
-                out["control"] = judged(
-                    train.reference_readings(ctx.config, ctx.mix, seed, "fp8"))
-                out["half_batch"] = judged(
-                    train.reference_readings(ctx.config, ctx.mix, seed, half=True))
+                out["control"] = judged(train.reference_readings(ctx, "fp8"))
+                out["half_batch"] = judged(train.reference_readings(ctx, half=True))
         out["setup_s"] = rec.setup_s
         out["total_s"] = time.perf_counter() - t0
         print(json.dumps(out), flush=True)

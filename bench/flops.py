@@ -1,7 +1,10 @@
 """Operations and bytes the algorithm needs, from shapes alone.
 
 Kept with the benchmark so that no change to the program can change how its
-work is counted.  A multiply-add counts as two operations.
+work is counted.  A multiply-add counts as two operations.  What a token
+costs in the model's layers and head is its family's to count
+(``bench/families/<family>.py``); ``paged_decode_call`` counts the program's
+GQA paged decode kernel.
 """
 
 from __future__ import annotations
@@ -11,38 +14,27 @@ import numpy as np
 BF16 = 2
 
 
-def matmul_params_per_layer(cfg: dict) -> int:
-    """Weights a token multiplies by in one layer (q, k, v, o and the MLP)."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    hq, hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    return d * hq * hd + 2 * d * hkv * hd + hq * hd * d + 3 * d * f
-
-
-def token_flops(cfg: dict, ctx: np.ndarray, head: bool = True) -> float:
+def token_flops(fam, cfg: dict, ctx: np.ndarray, head: bool = True) -> float:
     """Forward operations of tokens that attend over ``ctx`` earlier-or-own
-    positions each: the projections and MLP of every layer, the attention
-    scores and weighted sum, and the LM head where ``head``."""
+    positions each: every layer (the family's ``layer_flops``), and the LM
+    head where ``head``."""
     ctx = np.asarray(ctx, np.float64)
-    L = cfg["num_hidden_layers"]
-    hq, hd = cfg["num_attention_heads"], cfg["head_dim"]
-    per_tok = 2.0 * L * matmul_params_per_layer(cfg)
+    total = fam.layer_flops(cfg, ctx)
     if head:
-        per_tok += 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
-    return float(ctx.size * per_tok + (4.0 * L * hq * hd) * ctx.sum())
+        total += ctx.size * fam.head_flops(cfg)
+    return float(total)
 
 
-def prefill_flops(cfg: dict, plen: int) -> float:
+def prefill_flops(fam, cfg: dict, plen: int) -> float:
     """One prompt of ``plen`` tokens, causal, logits for its last token only."""
-    ctx = np.arange(1, plen + 1)
-    return (token_flops(cfg, ctx, head=False)
-            + 2.0 * cfg["hidden_size"] * cfg["vocab_size"])
+    return token_flops(fam, cfg, np.arange(1, plen + 1), head=False) + fam.head_flops(cfg)
 
 
-def train_flops_per_token(cfg: dict, seq_len: int) -> float:
+def train_flops_per_token(fam, cfg: dict, seq_len: int) -> float:
     """Forward and backward (three times the forward), causal attention over
     ``seq_len``, no recomputation counted."""
     ctx = np.arange(1, seq_len + 1)
-    return 3.0 * token_flops(cfg, ctx) / seq_len
+    return 3.0 * token_flops(fam, cfg, ctx) / seq_len
 
 
 def paged_decode_call(cfg: dict, seq_lens: np.ndarray, page: int) -> tuple[float, float]:

@@ -13,7 +13,7 @@ def read(run):
         return None
     total = 0.0
     for s in run.steps:
-        total += sum(prefill_flops(run.cfg, p[1]) for p in s["prefills"])
+        total += sum(prefill_flops(run.family, run.cfg, p[1]) for p in s["prefills"])
         if s["decode"]:
-            total += token_flops(run.cfg, np.asarray(s["decode"][2]) + 1)
+            total += token_flops(run.family, run.cfg, np.asarray(s["decode"][2]) + 1)
     return 100.0 * total / (run.window_s * run.peaks["bf16_flops_per_s"])

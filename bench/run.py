@@ -61,6 +61,11 @@ class Ctx:
     start_trace: Callable[[], None] = lambda: None
     stop_trace: Callable[[], None] = lambda: None
 
+    @property
+    def family(self):
+        """The configuration's family module (``spec.family``)."""
+        return spec.family(self.config, self.root / "bench")
+
 
 def find_devices(chips: int) -> dict:
     """The device JAX gives this process; a TPU with ``chips`` chips or an
@@ -100,6 +105,7 @@ def build_ctx(args, root: Path = ROOT) -> tuple[Ctx, dict]:
     cell = spec.cell(bm, args.workload)
     cfg = spec.config(bm, cell["config"], root)
     mix = spec.traffic(cell["traffic"], root / "bench")
+    spec.family(cfg, root / "bench")  # a missing family fails before set-up
     return Ctx(cell=cell, config=cfg, mix=mix, seed=args.seed,
                seconds=args.seconds, trace=bool(args.trace), t_start=T_START,
                trace_dir=Path(args.trace_dir),

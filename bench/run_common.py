@@ -21,6 +21,7 @@ class Record:
     kind: str
     cfg: dict
     mix: dict
+    family: Any = None  # the configuration's family module (spec.family)
     setup_s: float = 0.0
     window_s: float = 0.0
     end_s: float = 0.0

@@ -14,8 +14,8 @@ the time the step returned, and every client-side time is taken from that.
 
 from __future__ import annotations
 
-import dataclasses
 import gc
+import json
 import sys
 import time
 
@@ -28,31 +28,6 @@ from stats import returned_times
 
 PAGE = 16
 WARM_RID = 1 << 30  # warm-up requests, apart from the traffic's rids
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for ``cfg``: its registered arch, with every
-    number the benchmark's file states."""
-    from repro.config import get_arch
-
-    base = get_arch(cfg["arch"])
-    return dataclasses.replace(
-        base,
-        num_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"],
-        norm_eps=cfg["rms_norm_eps"],
-        rope_theta=float(cfg["rope_theta"]),
-        qkv_bias=cfg["attention_bias"],
-        qk_norm=cfg["qk_norm"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        dtype=cfg["torch_dtype"],
-        **cfg.get("program", {}),
-    )
 
 
 def kv_bytes_per_page(model, dev) -> float:
@@ -132,13 +107,13 @@ def run(ctx) -> Record:
     from repro.serving.router import ServeRouter
     from repro.serving.scheduler import Request
 
-    cfg, mix, seed, seconds = ctx.config, ctx.mix, ctx.seed, ctx.seconds
+    cfg, mix, seed, seconds, fam = ctx.config, ctx.mix, ctx.seed, ctx.seconds, ctx.family
     dev = jax.devices()[0]
-    rec = Record(kind="serve", cfg=cfg, mix=mix)
-    mcfg = model_config(cfg)
+    rec = Record(kind="serve", cfg=cfg, mix=mix, family=fam)
+    mcfg = fam.model_config(cfg)
     model = model_zoo.build_model(mcfg)
     with host_span("bench.weights"):
-        params = weights_mod.program_tree(cfg, model_zoo.param_specs(model), seed)
+        params = weights_mod.program_tree(fam, cfg, model_zoo.param_specs(model), seed)
         jax.block_until_ready(params)
     max_len = mix["prompt"]["max"] + mix["output"]["max"]
     probe = ContinuousBatchingEngine(mcfg, params, num_slots=1, page_size=PAGE,
@@ -280,11 +255,11 @@ def gaps(ctx, rec: Record, quant=None) -> tuple[float, float, int]:
 
     import reference
 
-    cfg = ctx.config
-    w = weights_mod.make(cfg, ctx.seed)
+    cfg, fam = ctx.config, ctx.family
+    w = weights_mod.make(fam, cfg, ctx.seed)
     S = rec.sizing["max_len"]
     n = rec.mix["output"]["max"]
-    items = reference.model_items(cfg)
+    cfg_json = json.dumps(cfg, sort_keys=True)
     worst = worst_ctrl = 0.0
     count = 0
     for rid in sample_requests(rec, ctx.seed, ctx.check_tokens):
@@ -295,7 +270,7 @@ def gaps(ctx, rec: Record, quant=None) -> tuple[float, float, int]:
         pos = np.full((n,), len(prompt) - 1, np.int32)
         pos[: len(served)] = np.arange(len(prompt) - 1, len(prompt) - 1 + len(served))
         gap, ctrl = jax.device_get(reference.served_gaps(
-            items, w, jnp.asarray(seq), jnp.asarray(pos), quant))
+            fam, cfg_json, w, jnp.asarray(seq), jnp.asarray(pos), quant))
         worst = max(worst, float(gap.max()))
         worst_ctrl = max(worst_ctrl, float(ctrl.max()))
         count += len(served)

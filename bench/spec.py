@@ -4,7 +4,8 @@ Everything that belongs to one configuration, one traffic mix or one metric
 is a file of its own:
 
   BENCHMARK.json                  cells, metrics, bounds
-  bench/configs/<config>.json     sizes as run, source, reduced, assumed
+  bench/configs/<config>.json     sizes as run, source, reduced, assumed, family
+  bench/families/<family>.py      what differs by architecture (reference.py)
   bench/traffic/<mix>.json        parameters for the one generator (traffic.py)
   bench/metrics/<metric>.py       ``read(run) -> float | None``
 
@@ -13,6 +14,7 @@ A new cell or metric is new files and new entries; no code here changes.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -72,13 +74,37 @@ def metrics_for(bm: dict, cell_name: str, trace: bool) -> list[dict]:
     return [m for m in group if cell_name in m.get("workloads", [cell_name])]
 
 
+def _load(path: Path, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str, bench: Path = BENCH):
     """``read`` of ``bench/metrics/<name>.py``, loaded by path (metric names
     hold dots, so they are not importable module names)."""
     path = bench / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SpecError(f"no reader {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"bench_metric_{name}").read
+
+
+def family(cfg: dict, bench: Path = BENCH):
+    """The module ``bench/families/<cfg["family"]>.py``: the weights'
+    leaves, the program's ModelConfig, the reference's forward pass and the
+    operations per token of the configuration's architecture (what it must
+    provide: ``reference``).  Loaded by path once per process, so that it
+    is one object to every caller."""
+    if "family" not in cfg:
+        raise SpecError(f'the configuration names no family: add "family": '
+                        f'"<name>" for {bench / "families"}/<name>.py')
+    path = bench / "families" / f"{cfg['family']}.py"
+    if not path.is_file():
+        raise SpecError(f"no family {path}")
+    return _family(path)
+
+
+@functools.cache
+def _family(path: Path):
+    return _load(path, f"bench_family_{path.stem}")

@@ -16,8 +16,9 @@ import spec
 import train
 
 TINY = dict(
-    arch="qwen2-0.5b", hidden_size=64, intermediate_size=128, num_attention_heads=4,
-    num_key_value_heads=2, head_dim=16, num_hidden_layers=2, vocab_size=512,
+    arch="qwen2-0.5b", family="qwen-dense", hidden_size=64, intermediate_size=128,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
+    vocab_size=512,
     rms_norm_eps=1e-6, rope_theta=1e6, attention_bias=True, qk_norm=False,
     tie_word_embeddings=True, torch_dtype="bfloat16",
     serve={"page_size": 16, "max_slots": 4, "headroom_bytes": 0},
@@ -119,8 +120,7 @@ def test_train_control_in_program_place_fails(control, monkeypatch, tmp_path):
     def control_run(ctx):
         rec = program_run(ctx)
         rec.extra["program"] = train.reference_readings(
-            ctx.config, ctx.mix, ctx.seed, quant="fp8" if control == "fp8" else None,
-            half=control == "half_batch")
+            ctx, quant="fp8" if control == "fp8" else None, half=control == "half_batch")
         return rec
 
     monkeypatch.setattr(train, "run", control_run)

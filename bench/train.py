@@ -19,17 +19,16 @@ import numpy as np
 import traffic as traffic_gen
 import weights as weights_mod
 from run_common import Compiles, Record, host_span, peak_bytes
-from serve import model_config
 
 CHECKED_STEPS = 3
 PARTITIONS = 256  # rows enough for set-up and any window, each row different
 
 
-def _configs(cfg: dict):
+def _configs(ctx):
     from repro.config import ParallelConfig, TrainConfig
 
-    t = cfg["train"]
-    mcfg = model_config(cfg)
+    t = ctx.config["train"]
+    mcfg = ctx.family.model_config(ctx.config)
     mcfg = dataclasses.replace(mcfg, remat=t["remat"])
     tcfg = TrainConfig(
         learning_rate=t["learning_rate"], warmup_steps=t["warmup_steps"],
@@ -83,11 +82,11 @@ def run(ctx) -> Record:
     from repro.models import model_zoo
     from repro.training.train_loop import make_train_step
 
-    cfg, mix, seed, seconds = ctx.config, ctx.mix, ctx.seed, ctx.seconds
+    cfg, mix, seed, seconds, fam = ctx.config, ctx.mix, ctx.seed, ctx.seconds, ctx.family
     t = cfg["train"]
     dev = jax.devices()[0]
-    rec = Record(kind="train", cfg=cfg, mix=mix)
-    mcfg, tcfg, pcfg = _configs(cfg)
+    rec = Record(kind="train", cfg=cfg, mix=mix, family=fam)
+    mcfg, tcfg, pcfg = _configs(ctx)
     mesh = single_device_mesh()
     bundle = make_train_step(mcfg, tcfg, pcfg, mesh)
     tokens_per_step = t["sequences_per_step"] * t["seq_len"]
@@ -99,7 +98,7 @@ def run(ctx) -> Record:
         with mesh:
             specs = model_zoo.param_specs(bundle.model)
             with host_span("bench.weights"):
-                params = weights_mod.program_tree(cfg, specs, seed)
+                params = weights_mod.program_tree(fam, cfg, specs, seed)
                 state = {"params": params,
                          "opt": jax.jit(bundle.optimizer.init)(params),
                          "step": jnp.zeros((), jnp.int32)}
@@ -124,7 +123,7 @@ def run(ctx) -> Record:
                     b1 = t["beta1"]
                     g1 = _leaf_norms(jax.tree.map(lambda m: m / (1 - b1),
                                                   state["opt"]["m"]))
-            p0 = weights_mod.program_tree(cfg, specs, seed)
+            p0 = weights_mod.program_tree(fam, cfg, specs, seed)
             delta = _leaf_norms(jax.tree.map(
                 lambda m, p: m - p.astype(jnp.float32), state["opt"]["master"], p0))
             del p0
@@ -162,7 +161,7 @@ def run(ctx) -> Record:
 
 
 def compare(program: dict, ref: dict, grad_floor: float = 1e-3) -> dict:
-    """The three numbers the check compares, from per-leaf norms.
+    """Three numbers from per-leaf norms (``limit_checks`` holds two).
 
     ``loss_gap``: the largest relative gap of a step's loss.  ``grad_gap``
     and ``update_gap``: by the worst leaf, |program norm - reference norm|
@@ -186,22 +185,22 @@ def compare(program: dict, ref: dict, grad_floor: float = 1e-3) -> dict:
             "left_out": sorted(set(ref["grad"]) - set(keep))}
 
 
-def reference_readings(cfg: dict, mix: dict, seed: int, quant=None,
-                       half: bool = False) -> dict:
+def reference_readings(ctx, quant=None, half: bool = False) -> dict:
     """The reference's losses, first clipped gradient and change after
-    ``CHECKED_STEPS`` steps, per leaf, on the loader's first batches.
-    ``half`` plants a fault: each step takes the mean over the first half of
-    its batch only."""
+    ``CHECKED_STEPS`` steps, per leaf, on the loader's first batches of the
+    cell ``ctx``.  ``half`` plants a fault: each step takes the mean over
+    the first half of its batch only."""
     import jax
     import jax.numpy as jnp
 
     import reference
 
-    w0 = {k: v.astype(jnp.float32) for k, v in weights_mod.make(cfg, seed).items()}
-    batches = [batch_rows(cfg, mix, seed, k) for k in range(CHECKED_STEPS)]
+    cfg, fam, seed = ctx.config, ctx.family, ctx.seed
+    w0 = {k: v.astype(jnp.float32) for k, v in weights_mod.make(fam, cfg, seed).items()}
+    batches = [batch_rows(cfg, ctx.mix, seed, k) for k in range(CHECKED_STEPS)]
     batches = [jnp.asarray(b[: len(b) // 2] if half else b) for b in batches]
     tcfg = dict(cfg["train"])
-    losses, g1, w3 = reference.adamw_steps(cfg, tcfg, w0, batches, quant)
+    losses, g1, w3 = reference.adamw_steps(fam, cfg, tcfg, w0, batches, quant)
     norm = jax.jit(lambda x: jnp.sqrt(jnp.sum(x * x)))
     out = {"loss": losses,
            "grad": {k: float(norm(v)) for k, v in g1.items()},
@@ -210,12 +209,14 @@ def reference_readings(cfg: dict, mix: dict, seed: int, quant=None,
 
 
 def limit_checks(c: dict, lim: dict) -> dict:
-    """``compare``'s numbers, each beside its limit."""
-    return {k: {"value": c[k], "max": lim[k]} for k in ("loss_gap", "grad_gap", "update_gap")}
+    """The numbers of ``compare`` that the check holds, each beside its limit.
+    ``loss_gap`` is not held: neither the float8 control nor a planted fault
+    reads three times what sound runs read on it."""
+    return {k: {"value": c[k], "max": lim[k]} for k in ("grad_gap", "update_gap")}
 
 
 def check(ctx, rec: Record) -> dict:
-    ref = reference_readings(ctx.config, ctx.mix, ctx.seed)
+    ref = reference_readings(ctx)
     c = compare(rec.extra["program"], ref)
     rec.extra["left_out"] = c["left_out"]
     return {"window_compiles": {"value": rec.compiles, "max": 0},

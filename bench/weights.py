@@ -1,11 +1,14 @@
 """Seeded random weights, made on the device in one jitted call.
 
 The benchmark, not the program, makes the weights, so the reference can make
-the very same values again from the seed.  Leaves are named by their path in
-the model (``layers.attn.q.w``) and stacked over layers on axis 0; matrices
-and biases are generated in bfloat16, the type they are served in, and norm
+the very same values again from the seed.  The configuration's family
+(``fam``, ``bench/families/<family>.py``) lists the leaves in ``leaf_specs``:
+named by their path in the model (``layers.attn.q.w``), stacked over layers
+on axis 0, each with a kind (matrix | bias | norm | embed).  Matrices and
+biases are generated in bfloat16, the type they are served in, and norm
 scales in float32.  ``program_tree`` lays them out as the program's parameter
-tree and pads the embedding to the program's padded vocabulary with zeros.
+tree and pads every vocabulary axis to the program's padded vocabulary with
+zeros.
 """
 
 from __future__ import annotations
@@ -19,33 +22,6 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(seed % 2**32), seed // 2**32)
 
 
-def leaf_specs(cfg: dict) -> dict[str, tuple[tuple, str]]:
-    """name -> (shape, kind); kind is matrix | bias | norm | embed."""
-    L, d, f = cfg["num_hidden_layers"], cfg["hidden_size"], cfg["intermediate_size"]
-    hq, hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    s = {
-        "embed.embedding": ((cfg["vocab_size"], d), "embed"),
-        "final_norm.scale": ((d,), "norm"),
-        "layers.ln1.scale": ((L, d), "norm"),
-        "layers.ln2.scale": ((L, d), "norm"),
-        "layers.attn.q.w": ((L, d, hq * hd), "matrix"),
-        "layers.attn.k.w": ((L, d, hkv * hd), "matrix"),
-        "layers.attn.v.w": ((L, d, hkv * hd), "matrix"),
-        "layers.attn.o.w": ((L, hq * hd, d), "matrix"),
-        "layers.mlp.gate.w": ((L, d, f), "matrix"),
-        "layers.mlp.up.w": ((L, d, f), "matrix"),
-        "layers.mlp.down.w": ((L, f, d), "matrix"),
-    }
-    if cfg["attention_bias"]:
-        s["layers.attn.q.b"] = ((L, hq * hd), "bias")
-        s["layers.attn.k.b"] = ((L, hkv * hd), "bias")
-        s["layers.attn.v.b"] = ((L, hkv * hd), "bias")
-    if cfg["qk_norm"]:
-        s["layers.attn.q_norm.scale"] = ((L, hd), "norm")
-        s["layers.attn.k_norm.scale"] = ((L, hd), "norm")
-    return s
-
-
 def _leaf(key, shape, kind):
     if kind == "norm":
         return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
@@ -56,46 +32,49 @@ def _leaf(key, shape, kind):
     return jax.random.normal(key, shape, jnp.bfloat16) * scale
 
 
-def generate(cfg: dict, key: jax.Array) -> dict[str, jax.Array]:
+def generate(fam, cfg: dict, key: jax.Array) -> dict[str, jax.Array]:
     """Traceable: every leaf from its own fold of ``key``."""
-    specs = leaf_specs(cfg)
+    specs = fam.leaf_specs(cfg)
     return {
         name: _leaf(jax.random.fold_in(key, i), shape, kind)
         for i, (name, (shape, kind)) in enumerate(sorted(specs.items()))
     }
 
 
-def make(cfg: dict, seed: int) -> dict[str, jax.Array]:
+def make(fam, cfg: dict, seed: int) -> dict[str, jax.Array]:
     """The flat leaves on the default device, in one jitted call."""
-    return jax.jit(lambda k: generate(cfg, k))(seed_key(seed))
+    return jax.jit(lambda k: generate(fam, cfg, k))(seed_key(seed))
 
 
 def _path_name(path) -> str:
     return ".".join(str(getattr(p, "key", p)) for p in path)
 
 
-def program_tree(cfg: dict, program_specs, seed: int):
+def program_tree(fam, cfg: dict, program_specs, seed: int):
     """The same leaves, laid out as the program's parameter tree
     (``program_specs``: its tree of ShapeDtypeStruct), in one jitted call.
     A leaf the program has and the benchmark does not make is an error."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(program_specs)
     names = [_path_name(p) for p, _ in flat]
-    want = leaf_specs(cfg)
+    want = fam.leaf_specs(cfg)
     missing = sorted(set(names) - set(want))
     extra = sorted(set(want) - set(names))
     if missing or extra:
         raise ValueError(f"parameter trees differ: program only {missing}, "
                          f"benchmark only {extra}")
 
+    vocab = cfg["vocab_size"]
+
     def build(key):
-        leaves = generate(cfg, key)
+        leaves = generate(fam, cfg, key)
         out = []
         for name, (_, sds) in zip(names, flat):
             x = leaves[name]
             if x.shape != sds.shape:  # the program pads the vocabulary
-                if name != "embed.embedding" or x.shape[1:] != sds.shape[1:]:
+                if len(x.shape) != len(sds.shape) or any(
+                        a != b and not (a == vocab < b) for a, b in zip(x.shape, sds.shape)):
                     raise ValueError(f"{name}: {x.shape} vs program {sds.shape}")
-                x = jnp.pad(x, ((0, sds.shape[0] - x.shape[0]), (0, 0)))
+                x = jnp.pad(x, [(0, b - a) for a, b in zip(x.shape, sds.shape)])
             out.append(x.astype(sds.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
