@@ -9,7 +9,11 @@ diagonal, and positions come from the kernel's indices (query i sees keys
 0..i), so callers pass positions 0..S-1.
 
 GQA: splash's MQA form takes G query heads over one K/V head; it is vmapped
-over batch and KV heads.  q is scaled by ``1/sqrt(D)`` in its own dtype and
+over batch and KV heads.  The forward kernel's output and logsumexp carry
+the ``checkpoint_name`` ``RESIDUALS``, so a remat policy that saves that
+name keeps them for the backward instead of rerunning the forward kernel
+(``models.transformer._maybe_remat``); outside a ``jax.checkpoint`` the
+name changes nothing.  q is scaled by ``1/sqrt(D)`` in its own dtype and
 nothing is cast up before a dot: the MXU takes bf16 operands and
 accumulates in f32.  Used by ``models.attention.attend``.
 """
@@ -27,6 +31,8 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 # splash tiles are multiples of the 128-lane vreg
 BLOCK_SIZES = (512, 256, 128)
+# checkpoint name of the forward kernel's output and logsumexp
+RESIDUALS = "flash_attention_residuals"
 
 
 def block_size(seq_len: int) -> int:
@@ -52,7 +58,8 @@ def _kernel(seq_len: int, group: int, causal: bool, interpret: bool):
     # mask arrays are constants and not that trace's tracers
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mqa_single_device(
-            splash.MultiHeadMask([one] * group), block_sizes=sizes, interpret=interpret,
+            splash.MultiHeadMask([one] * group), block_sizes=sizes,
+            residual_checkpoint_name=RESIDUALS, interpret=interpret,
         )
 
 

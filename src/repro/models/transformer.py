@@ -124,7 +124,14 @@ def _maybe_remat(fn, cfg: ModelConfig):
     if cfg.remat == "full":
         policy = jax.checkpoint_policies.nothing_saveable
     else:
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        # the flash kernel's output and logsumexp are a pallas_call's, which
+        # the dots policy alone would recompute by rerunning the forward
+        # kernel in the backward; only the flash route names them
+        from repro.kernels.flash_attention.ops import RESIDUALS
+
+        cp = jax.checkpoint_policies
+        policy = cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable, cp.save_only_these_names(RESIDUALS))
     return jax.checkpoint(fn, policy=policy)
 
 

@@ -223,3 +223,55 @@ def test_decode_position_mask_property(qlen_unused, pos0):
     kv_pos = jnp.broadcast_to(jnp.arange(T), (B, T))
     out = attention.sdpa(q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=True)
     assert float(jnp.max(jnp.abs(out))) < 100.0  # poison never leaks
+
+
+def _pallas_calls(jaxpr, seen=None):
+    """Names of the ``pallas_call``s in a jaxpr, its sub-jaxprs included."""
+    seen = [] if seen is None else seen
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            seen.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    _pallas_calls(sub.jaxpr, seen)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    _pallas_calls(sub, seen)
+    return seen
+
+
+def test_dots_remat_saves_the_flash_residuals(monkeypatch):
+    """Two flash-routed layers under the ``dots`` remat: saving the forward
+    kernel's output and logsumexp by name leaves one forward kernel per
+    backward (the dots policy alone reruns it) and changes no loss or
+    gradient."""
+    from repro.models import model_zoo
+    from repro.training.train_loop import make_loss_and_grad
+
+    cfg = _cfg(num_layers=2, remat="dots")
+    model = model_zoo.build_model(cfg)
+    params = jax.tree.map(lambda p: p.astype(jnp.float32),
+                          model_zoo.init_params(model, jax.random.PRNGKey(11)))
+    toks = jax.random.randint(jax.random.PRNGKey(12), (2, 129), 0, cfg.vocab_size)
+    batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+    def run():
+        fn = make_loss_and_grad(model, 1)
+        calls = _pallas_calls(jax.make_jaxpr(fn)(params, batch).jaxpr)
+        loss, _, grads = jax.jit(fn)(params, batch)
+        return calls, loss, grads
+
+    calls, loss, grads = run()
+    with monkeypatch.context() as m:  # the dots policy alone
+        m.setattr(jax.checkpoint_policies, "save_from_both_policies", lambda dots, _: dots)
+        dots_calls, dots_loss, dots_grads = run()
+    fwd = lambda c: c.count("splash_mqa_fwd_residuals")  # noqa: E731
+    assert [c.count("splash_mqa_dkv_no_residuals") for c in (calls, dots_calls)] == [1, 1]
+    assert (fwd(calls), fwd(dots_calls)) == (1, 2)
+    np.testing.assert_allclose(float(loss), float(dots_loss), rtol=1e-6)
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, r: np.testing.assert_allclose(
+            np.asarray(g), np.asarray(r), atol=1e-6, rtol=1e-5,
+            err_msg=jax.tree_util.keystr(path)),
+        grads, dots_grads)

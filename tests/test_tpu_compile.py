@@ -8,7 +8,8 @@ qwen2-0.5b's decode attention (8 slots, 2 KV heads x 7 query heads of width
 step, and the flash attention's forward and backward at the train cell's
 2 x 2048 tokens (qwen2-0.5b's 14/2 heads of width 64, qwen3-4b's 32/8 of
 width 128).  A two-layer train step at qwen2-0.5b's widths checks that the
-route puts the kernel in the step and no score matrix.  The topology is
+route puts the kernel in the step and no score matrix, and that the forward
+kernel runs once per backward.  The topology is
 described inside a fixture, never at import, so every test worker collects
 the same tests and only the worker that runs them loads the TPU compiler.
 """
@@ -115,9 +116,9 @@ def test_kernel_compiles_for_v5e(one_chip, build):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_train_step_takes_the_flash_kernel_for_v5e(one_chip, monkeypatch):
-    """Two layers of qwen2-0.5b at 2 x 2048 tokens: the step holds the
-    kernel's forward, dq and dkv calls and no f32 score matrix."""
+def _train_step_hlo(one_chip, monkeypatch) -> str:
+    """The compiled HLO of a train step of two qwen2-0.5b layers, one
+    microbatch of 2 x 2048 tokens, ``dots`` remat, on the flash route."""
     from repro.config import ParallelConfig, TrainConfig, get_arch
     from repro.models import attention
     from repro.training.train_loop import make_train_step, state_shardings
@@ -133,9 +134,25 @@ def test_train_step_takes_the_flash_kernel_for_v5e(one_chip, monkeypatch):
         jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0)), state_shardings(bundle, mesh))
     tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec()))
     with mesh:
-        text = jax.jit(bundle.train_step).lower(
+        return jax.jit(bundle.train_step).lower(
             state, {"tokens": tok, "targets": tok}).compile().as_text()
-    kernels = set(re.findall(r"%(splash_mqa_\w+?)(?:\.\d+)? = ", text))
-    assert kernels == {"splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
-                       "splash_mqa_dkv_no_residuals"}
+
+
+def _splash_calls(text: str) -> list:
+    return re.findall(r"%(splash_mqa_\w+?)(?:\.\d+)? = ", text)
+
+
+def test_train_step_takes_the_flash_kernel_for_v5e(one_chip, monkeypatch):
+    """The step holds the kernel's forward, dq and dkv calls and no f32
+    score matrix."""
+    text = _train_step_hlo(one_chip, monkeypatch)
+    assert set(_splash_calls(text)) == {"splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                                        "splash_mqa_dkv_no_residuals"}
     assert not re.search(r"f32\[[\d,]*2048,2048\]", text)
+
+
+def test_train_step_runs_the_flash_forward_once_for_v5e(one_chip, monkeypatch):
+    """The ``dots`` remat keeps the forward kernel's output and logsumexp,
+    so the backward takes them and does not rerun the forward kernel."""
+    calls = _splash_calls(_train_step_hlo(one_chip, monkeypatch))
+    assert calls.count("splash_mqa_fwd_residuals") == calls.count("splash_mqa_dkv_no_residuals")
